@@ -18,6 +18,7 @@ from repro.core.tuners import GridSearchSpace, SHATuner
 from repro.data import DataPipeline, synthetic_cifar
 from repro.models.resnet import ResNet
 from repro.train.jax_trainer import JaxTrainer
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def make_backend():
@@ -40,6 +41,7 @@ def space():
 
 
 def main():
+    enable_compile_cache()
     trials = space().trials(100)
     print(f"{len(trials)} trials × 100 steps, p = {merge_rate(trials):.2f}")
 

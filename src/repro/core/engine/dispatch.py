@@ -65,7 +65,8 @@ from repro.core.stagetree import (Stage, StageTreeBuilder,
                                   sibling_chain_groups, sibling_groups)
 from repro.core.engine.events import EventLoop
 from repro.core.faults import WorkerCrashed, is_transient, raw_store
-from repro.core.trainer import StageContext, TrainerBackend
+from repro.core.trainer import (BatchIncompatible, StageContext,
+                                 TrainerBackend)
 from repro.dist.meshes import WorkerMesh
 from repro.train.checkpoint import CheckpointStore
 
@@ -749,7 +750,7 @@ class Dispatcher:
             try:
                 bstates = self.backend.run_chain(state, ctxs)
                 fused = True
-            except ValueError:
+            except BatchIncompatible:
                 # in-flight incompatibility: per-stage fallback, same
                 # semantics, no fusion credit
                 fused = False
@@ -908,7 +909,7 @@ class Dispatcher:
                 else:
                     outs = self.backend.run_chains_batched(states, ctx_chains)
                 batched = True
-            except ValueError:
+            except BatchIncompatible:
                 # in-flight incompatibility (e.g. divergent restored batch
                 # sizes): fall back to member-sequential execution — same
                 # semantics, no batching credit
@@ -1069,16 +1070,9 @@ class Dispatcher:
                 continue
             wall0 = _time.perf_counter()
             try:
-                try:
-                    out = self.backend.run_chain(
-                        self.backend.clone_state(s), ctxs)
-                except ValueError:
-                    # per-stage fallback, same semantics as run_chain
-                    out, ss = [], self.backend.clone_state(s)
-                    for st, ctx in zip(chain, ctxs):
-                        if st.steps > 0:
-                            ss = self.backend.run_stage(ss, ctx)
-                        out.append(ss)
+                # one member: nothing to be incompatible with
+                out = self.backend.run_chain(self.backend.clone_state(s),
+                                             ctxs)
             except Exception as exc:
                 back = self._fail_unit(
                     worker, chain, exc, t,

@@ -13,10 +13,10 @@ per-member solo execution, and every failed attempt's cost lands in
 ``EngineStats.wasted_gpu_seconds`` (never split-charged to the sharing
 studies' fair-share accounts).
 
-Fault taxonomy (all derive from :class:`FaultError`, and deliberately NOT
-from ``ValueError`` — the dispatcher and backends use ``ValueError`` as
-the in-flight "fall back to unfused/unbatched execution" signal, which
-must stay distinguishable from an injected failure):
+Fault taxonomy (all derive from :class:`FaultError`, and NOT from
+:class:`~repro.core.trainer.BatchIncompatible`, the in-flight "fall back
+to unbatched execution" signal, which must stay distinguishable from an
+injected failure):
 
 * :class:`TransientStageError` — one execution attempt failed (flaky
   kernel, OOM race, preempted slice); retry is expected to succeed.

@@ -32,7 +32,18 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.values import desc_static, desc_values
 
-__all__ = ["TrainerBackend", "SimulatedTrainer", "StageContext"]
+__all__ = ["TrainerBackend", "SimulatedTrainer", "StageContext",
+           "BatchIncompatible"]
+
+
+class BatchIncompatible(Exception):
+    """A batched or fused call cannot take its members together (they
+    diverge in step range, static hps, hp names or batch size).  The
+    dispatcher then runs them one at a time, with the same semantics.
+
+    Deliberately not a ``ValueError``: only this class triggers that
+    fallback, so a compile or lowering error from the backend propagates
+    instead of silently costing the batching."""
 
 
 @dataclass(frozen=True)

@@ -53,6 +53,10 @@ NEG_INF = -1e30
 # LSE filler for rows that saw no valid key (and for padded Q rows in the
 # backward): exp(s - BIG) == 0 for any finite tile score s.
 LSE_EMPTY = 1e30
+# Per-row statistics (LSE, delta) cross the kernel boundary broadcast over
+# one lane tile, (…, Sq, 128): a TPU block's last two dims must be
+# (8k, 128m) or the whole array, which a (…, bq) row block is not.
+_LANES = 128
 
 
 def _tile_live(qi, ki, *, causal: bool, window: int, bq: int, bk: int,
@@ -158,7 +162,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, tiles_ref,
         safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_scr[...] / safe).astype(o_ref.dtype)
         lse = jnp.where(l == 0.0, LSE_EMPTY, m_scr[...] + jnp.log(safe))
-        lse_ref[0, 0] = lse[:, 0]
+        lse_ref[0, 0] = jnp.broadcast_to(lse, (bq, _LANES))
 
 
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -210,12 +214,12 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1), lambda b, h, i, j: (0, 0)),
+            pl.BlockSpec((1, 1, bq, _LANES), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalar tile counter
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sqp, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sqp), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sqp, _LANES), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         scratch_shapes=[
@@ -227,6 +231,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     )(qt, kt, vt)
 
     out = out.transpose(0, 2, 1, 3)
+    lse = lse[..., 0]
     if pq:
         out = out[:, :Sq]
         lse = lse[:, :, :Sq]
@@ -260,8 +265,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)                      # (BK, hd)
         v = v_ref[0, 0].astype(jnp.float32)                      # (BK, hd)
         do = do_ref[0, 0].astype(jnp.float32)                    # (BQ, hd)
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]         # (BQ, 1)
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]     # (BQ, 1)
+        lse = lse_ref[0, 0][:, :1]                               # (BQ, 1)
+        delta = delta_ref[0, 0][:, :1]                           # (BQ, 1)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -306,8 +311,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)                      # (BK, hd)
         v = v_ref[0, 0].astype(jnp.float32)                      # (BK, hd)
         do = do_ref[0, 0].astype(jnp.float32)                    # (BQ, hd)
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]         # (BQ, 1)
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]     # (BQ, 1)
+        lse = lse_ref[0, 0][:, :1]                               # (BQ, 1)
+        delta = delta_ref[0, 0][:, :1]                           # (BQ, 1)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -374,6 +379,9 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kt = kp.transpose(0, 2, 1, 3)                                # (B,Hkv,Skp,hd)
     vt = vp.transpose(0, 2, 1, 3)
     deltat = deltap.transpose(0, 2, 1)                           # (B,Hq,Sqp)
+    lanes = lambda r: jnp.broadcast_to(r.astype(jnp.float32)[..., None],
+                                       r.shape + (_LANES,))
+    lsep, deltat = lanes(lsep), lanes(deltat)                    # (…,Sqp,128)
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -381,7 +389,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0))
     kv_spec_q = pl.BlockSpec((1, 1, bk, hd),
                              lambda b, h, i, j: (b, h // group, j, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, bq, _LANES), lambda b, h, i, j: (b, h, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
@@ -401,7 +409,8 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kv_spec_t = pl.BlockSpec((1, 1, bk, hd),
                              lambda b, h, j, i: (b, h // group, j, 0))
     kv_out_t = pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, i: (b, h, j, 0))
-    row_spec_t = pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i))
+    row_spec_t = pl.BlockSpec((1, 1, bq, _LANES),
+                              lambda b, h, j, i: (b, h, i, 0))
 
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,

@@ -6,7 +6,7 @@ parameter leaves × members.  This kernel fuses one leaf's update across
 every member into a single launch: the member-stacked leaf is viewed as
 ``(M, R, 128)`` lanes, the grid is ``(M, R/BR)``, and the divergent
 per-member hyper-parameters (lr, wd, momentum, b1/b2/eps) ride in as
-``(M, 1)`` vector operands indexed by the member grid axis — exactly the
+whole ``(M,)`` SMEM operands indexed by the member grid axis — exactly the
 "divergent hp values, one compile per group" contract the data plane
 already guarantees for the loss.
 
@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.custom_batching import custom_vmap
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops as kops
 from repro.train.optimizer import apply_update
@@ -45,21 +46,23 @@ _MAX_ROWS = 64   # block rows → ≤ 64·128 elements per grid step
 
 
 def _sgd_kernel(p_ref, g_ref, lr_ref, wd_ref, o_ref):
+    i = pl.program_id(0)
     p = p_ref[0].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
-    lr = lr_ref[0, 0]
-    wd = wd_ref[0, 0]
+    lr = lr_ref[i]
+    wd = wd_ref[i]
     o_ref[0] = (p - lr * (g + wd * p)).astype(o_ref.dtype)
 
 
 def _momentum_kernel(p_ref, g_ref, m_ref, lr_ref, wd_ref, mom_ref,
                      op_ref, om_ref):
+    i = pl.program_id(0)
     p = p_ref[0].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
     m = m_ref[0].astype(jnp.float32)
-    lr = lr_ref[0, 0]
-    wd = wd_ref[0, 0]
-    mom = mom_ref[0, 0]
+    lr = lr_ref[i]
+    wd = wd_ref[i]
+    mom = mom_ref[i]
     m2 = mom * m + g
     om_ref[0] = m2.astype(om_ref.dtype)
     op_ref[0] = (p - lr * (m2 + wd * p)).astype(op_ref.dtype)
@@ -68,21 +71,22 @@ def _momentum_kernel(p_ref, g_ref, m_ref, lr_ref, wd_ref, mom_ref,
 def _adam_kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, wd_ref, b1_ref,
                  b2_ref, eps_ref, bc1_ref, bc2_ref, op_ref, om_ref, ov_ref,
                  *, decoupled: bool):
+    i = pl.program_id(0)
     p = p_ref[0].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
     m = m_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    lr = lr_ref[0, 0]
-    wd = wd_ref[0, 0]
-    b1 = b1_ref[0, 0]
-    b2 = b2_ref[0, 0]
-    eps = eps_ref[0, 0]
+    lr = lr_ref[i]
+    wd = wd_ref[i]
+    b1 = b1_ref[i]
+    b2 = b2_ref[i]
+    eps = eps_ref[i]
     m2 = b1 * m + (1 - b1) * g
     v2 = b2 * v + (1 - b2) * g * g
     om_ref[0] = m2.astype(om_ref.dtype)
     ov_ref[0] = v2.astype(ov_ref.dtype)
-    mh = m2 / bc1_ref[0, 0]
-    vh = v2 / bc2_ref[0, 0]
+    mh = m2 / bc1_ref[i]
+    vh = v2 / bc2_ref[i]
     if decoupled:   # adamw
         upd = p - lr * (mh / (jnp.sqrt(vh) + eps) + wd * p)
     else:           # adam: wd folded into the gradient (L2)
@@ -124,7 +128,9 @@ def _stacked_leaf_update(name: str, *args, interpret: Optional[bool] = None):
         interpret = jax.default_backend() == "cpu"
 
     blk = pl.BlockSpec((1, br, _LANE), lambda i, j: (i, j, 0))
-    sblk = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    # per-member scalars stay whole in SMEM, read at the member grid index:
+    # a (1, 1) VMEM block of an (M, 1) array is no legal TPU tile for M > 1
+    sblk = pl.BlockSpec(memory_space=pltpu.SMEM)
     out_shape = [jax.ShapeDtypeStruct((M, Rp, _LANE), arrs[i].dtype)
                  for i in range(nout)]
     outs = pl.pallas_call(
@@ -135,7 +141,7 @@ def _stacked_leaf_update(name: str, *args, interpret: Optional[bool] = None):
         out_shape=out_shape if nout > 1 else out_shape[0],
         interpret=interpret,
     )(*[lanes(a) for a in arrs],
-      *[s.reshape(M, 1).astype(jnp.float32) for s in scals])
+      *[s.reshape(M).astype(jnp.float32) for s in scals])
 
     def unlanes(o):
         flat = o.reshape(M, Rp * _LANE)

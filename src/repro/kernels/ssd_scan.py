@@ -44,7 +44,7 @@ __all__ = ["ssd_intra_pallas", "ssd_intra_bwd_pallas"]
 
 
 def _ssd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, o_ref, *, q: int):
-    x = x_ref[0, 0, :, 0].astype(jnp.float32)        # (Q, P)
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)         # (Q,)
     cum = cum_ref[0, 0, 0].astype(jnp.float32)       # (Q,)
     Bm = b_ref[0, 0].astype(jnp.float32)             # (Q, N)
@@ -58,7 +58,7 @@ def _ssd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, o_ref, *, q: int):
     decay = jnp.where(tril, jnp.exp(seg), 0.0)
     att = cb * decay * dt[None, :]
     y = jax.lax.dot_general(att, x, (((1,), (0,)), ((), ())))    # (Q, P)
-    o_ref[0, 0, :, 0] = y.astype(o_ref.dtype)
+    o_ref[0, 0] = y.astype(o_ref.dtype)
 
 
 def ssd_intra_pallas(xr: jnp.ndarray, dtr: jnp.ndarray, ltT: jnp.ndarray,
@@ -88,17 +88,17 @@ def ssd_intra_pallas(xr: jnp.ndarray, dtr: jnp.ndarray, ltT: jnp.ndarray,
         functools.partial(_ssd_kernel, q=Q),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, Q, 1, P), lambda bc, h: (bc, h, 0, 0, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda bc, h: (bc, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q), lambda bc, h: (bc, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q), lambda bc, h: (bc, h, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda bc, h: (bc, 0, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda bc, h: (bc, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, Q, 1, P), lambda bc, h: (bc, h, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * nc, H, Q, 1, P), xr.dtype),
+        out_specs=pl.BlockSpec((1, 1, Q, P), lambda bc, h: (bc, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * nc, H, Q, P), xr.dtype),
         interpret=interpret,
     )(
-        x_hm.reshape(B * nc, H, Q, 1, P),
+        x_hm.reshape(B * nc, H, Q, P),
         dt_hm.reshape(B * nc, H, 1, Q),
         cum.reshape(B * nc, H, 1, Q),
         Br.reshape(B * nc, 1, Q, N),
@@ -114,12 +114,12 @@ def _ssd_bwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, g_ref,
     h = pl.program_id(1)
     nh = pl.num_programs(1)
 
-    x = x_ref[0, 0, :, 0].astype(jnp.float32)        # (Q, P)
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)         # (Q,)
     cum = cum_ref[0, 0, 0].astype(jnp.float32)       # (Q,)
     Bm = b_ref[0, 0].astype(jnp.float32)             # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)             # (Q, N)
-    g = g_ref[0, 0, :, 0].astype(jnp.float32)        # (Q, P)
+    g = g_ref[0, 0].astype(jnp.float32)              # (Q, P)
 
     # recompute the forward tile
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # (Q, Q)
@@ -141,7 +141,7 @@ def _ssd_bwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, g_ref,
     dseg = dad * cb * dt[None, :]                                # through exp
     dcum = jnp.sum(dseg, axis=1) - jnp.sum(dseg, axis=0)         # (Q,)
 
-    dx_ref[0, 0, :, 0] = dx.astype(dx_ref.dtype)
+    dx_ref[0, 0] = dx.astype(dx_ref.dtype)
     ddt_ref[0, 0, 0] = ddt.astype(ddt_ref.dtype)
     dcum_ref[0, 0, 0] = dcum.astype(dcum_ref.dtype)
 
@@ -183,7 +183,7 @@ def ssd_intra_bwd_pallas(xr: jnp.ndarray, dtr: jnp.ndarray, ltT: jnp.ndarray,
         interpret = jax.default_backend() == "cpu"
 
     grid = (B * nc, H)
-    x_spec = pl.BlockSpec((1, 1, Q, 1, P), lambda bc, h: (bc, h, 0, 0, 0))
+    x_spec = pl.BlockSpec((1, 1, Q, P), lambda bc, h: (bc, h, 0, 0))
     row_spec = pl.BlockSpec((1, 1, 1, Q), lambda bc, h: (bc, h, 0, 0))
     bc_spec = pl.BlockSpec((1, 1, Q, N), lambda bc, h: (bc, 0, 0, 0))
     dx, ddt, dcum, db, dc = pl.pallas_call(
@@ -192,7 +192,7 @@ def ssd_intra_bwd_pallas(xr: jnp.ndarray, dtr: jnp.ndarray, ltT: jnp.ndarray,
         in_specs=[x_spec, row_spec, row_spec, bc_spec, bc_spec, x_spec],
         out_specs=[x_spec, row_spec, row_spec, bc_spec, bc_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B * nc, H, Q, 1, P), xr.dtype),
+            jax.ShapeDtypeStruct((B * nc, H, Q, P), xr.dtype),
             jax.ShapeDtypeStruct((B * nc, H, 1, Q), dtr.dtype),
             jax.ShapeDtypeStruct((B * nc, H, 1, Q), jnp.float32),
             jax.ShapeDtypeStruct((B * nc, 1, Q, N), Br.dtype),
@@ -201,12 +201,12 @@ def ssd_intra_bwd_pallas(xr: jnp.ndarray, dtr: jnp.ndarray, ltT: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((Q, Q), jnp.float32)],
         interpret=interpret,
     )(
-        x_hm.reshape(B * nc, H, Q, 1, P),
+        x_hm.reshape(B * nc, H, Q, P),
         dt_hm.reshape(B * nc, H, 1, Q),
         cum.reshape(B * nc, H, 1, Q),
         Br.reshape(B * nc, 1, Q, N),
         Cr.reshape(B * nc, 1, Q, N),
-        g_hm.reshape(B * nc, H, Q, 1, P),
+        g_hm.reshape(B * nc, H, Q, P),
     )
 
     dxr = jnp.moveaxis(dx.reshape(B, nc, H, Q, P), 2, 3)

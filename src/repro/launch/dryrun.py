@@ -41,12 +41,6 @@ __all__ = ["run_case", "main"]
 _ns = shardings_for
 
 
-def _mesh_context(mesh):
-    """``jax.set_mesh`` where available (jax >= 0.6); older releases use the
-    ``Mesh`` object itself as the context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def _collect(lowered, compiled) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     try:
@@ -125,7 +119,7 @@ def run_case(arch: str, shape_name: str, *, multi_pod: bool = False,
     scalar = NamedSharding(mesh, P())
     kind, kw = input_specs(cfg, shape)
 
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opt_shape = jax.eval_shape(
                 lambda p: init_opt_state("adamw", p), params_shape)

@@ -26,8 +26,10 @@ Examples::
 ``--snapshot-at T`` drives the deployment to global virtual time ``T``,
 snapshots the whole gateway envelope (every session + admission state +
 lease table), then **kills the live gateway** and finishes from the
-snapshot via ``StudyGateway.restore``.  Uses the simulator backend; swap
-``SimulatedTrainer`` for ``JaxTrainer`` to serve real training.
+snapshot via ``StudyGateway.restore``.  Every session trains on the
+simulator backend (``SimulatedTrainer``, virtual clock): this launcher
+serves no real training.  ``chip_smoke.py`` drives a real ``JaxTrainer``
+study through the same ``StudyService`` path.
 """
 
 from __future__ import annotations

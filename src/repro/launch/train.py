@@ -25,6 +25,7 @@ from repro.launch.specs import batch_struct
 from repro.models import LM
 from repro.train.optimizer import init_opt_state
 from repro.train.step import build_train_step, shardings_for
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def local_mesh():
@@ -35,7 +36,12 @@ def local_mesh():
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    # Auto axes: the compiler propagates placements from the committed
+    # params and batch.  Explicit axes (jax.make_mesh's default) type every
+    # op's output sharding and refuse the embedding gather, whose operands
+    # put ``data`` on both the batch and the d_model dimension.
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def main():
@@ -49,6 +55,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--use-kernel", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -81,13 +81,15 @@ construction) for ``EngineStats``.
 
 Mesh workers (distribution plane v2): :meth:`set_mesh` binds the trainer
 to the dispatching worker's :class:`~repro.dist.meshes.WorkerMesh` before
-each work unit.  A ``None`` or 1-device mesh is the default path —
-bit-identical to thread-worker execution.  On a wider mesh the fused
+each work unit.  A ``None`` mesh runs on the default device; a 1-device
+mesh runs the same unsharded path on the device it owns — bit-identical
+to thread-worker execution.  On a wider mesh the fused
 carry lives **sharded at rest**: ``(params, opt)`` is placed with
 :func:`repro.dist.sharding.generic_param_specs` (largest dividing dim →
 ``fsdp`` axis, largest remaining → ``tp``; PR 3's divisibility gate);
-every chunk executable is wrapped to all-gather the carry to replicated
-before the arithmetic, and the output re-scatters to the at-rest
+every chunk executable runs its body under ``shard_map`` on replicated
+operands (the carry is all-gathered at entry; the TPU compiler cannot
+partition a Pallas kernel itself), and the output re-scatters to the at-rest
 placement *between* executables (``device_put``) — sharding is pure data
 movement, so on CPU the sharded path stays bit-identical to the
 unsharded one while the carry demonstrably lives distributed between
@@ -108,7 +110,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.trainer import StageContext, TrainerBackend
+from repro.core.trainer import (BatchIncompatible, StageContext,
+                                 TrainerBackend)
 from repro.core.values import desc_static, desc_values
 from repro.data.pipeline import DataPipeline
 from repro.dist.sharding import generic_param_specs
@@ -186,6 +189,7 @@ class JaxTrainer(TrainerBackend):
         # -------- mesh plane (distribution plane v2; see module docstring)
         self._wmesh = None                      # live WorkerMesh (>1 device)
         self._mesh = None                       # its jax.sharding.Mesh
+        self._device = None                     # a 1-device mesh's device
         self._mesh_key: Optional[Tuple] = None  # joins executable cache keys
         self._meshes: Dict[Tuple, Any] = {}     # WorkerMesh.key -> jax Mesh
         self._mesh_ok: Dict[Tuple, bool] = {}   # mesh_compatible verdicts
@@ -206,11 +210,15 @@ class JaxTrainer(TrainerBackend):
     def set_mesh(self, mesh) -> None:
         """Bind to the dispatching worker's mesh (None = thread worker).
 
-        1-device meshes take the default path — no sharding, no new cache
-        entries — so a 1-device-mesh fleet is bit- and stats-identical to
-        a thread fleet.  Wider meshes build (and cache) the live
-        ``jax.sharding.Mesh`` once per distinct ``WorkerMesh.key``."""
-        if mesh is None or mesh.n_devices == 1:
+        A thread worker runs on the default device.  A 1-device mesh runs
+        unsharded on the device it owns: the carry is placed there and
+        the executables, keyed by the mesh, are compiled for it, so a
+        fleet of 1-chip workers spreads over the chips instead of sharing
+        device 0.  The arithmetic is the thread worker's, bit for bit.
+        The live ``jax.sharding.Mesh`` is built (and cached) once per
+        distinct ``WorkerMesh.key``."""
+        self._device = None
+        if mesh is None:
             self._wmesh = self._mesh = self._mesh_key = None
             return
         key = mesh.key
@@ -218,7 +226,12 @@ class JaxTrainer(TrainerBackend):
         if m is None:
             m = mesh.jax_mesh()
             self._meshes[key] = m
-        self._wmesh, self._mesh, self._mesh_key = mesh, m, key
+        self._mesh_key = key
+        if mesh.n_devices == 1:
+            self._wmesh = self._mesh = None
+            self._device = m.devices.flat[0]
+        else:
+            self._wmesh, self._mesh = mesh, m
 
     def mesh_compatible(self, mesh, ctxs) -> bool:
         """PR 3's divisibility gate as a placement gate: a >1-device mesh
@@ -267,30 +280,23 @@ class JaxTrainer(TrainerBackend):
         return jax.tree.map(lambda s: NamedSharding(self._mesh, s), specs,
                             is_leaf=lambda x: isinstance(x, P))
 
-    def _meshed_build(self, build, carry, n_lead: int):
-        """Wrap a chunk-body builder for mesh execution: the carry enters
-        sharded at rest and is gathered to replicated before the
-        arithmetic — pure data movement, so the body stays CPU-bitwise
-        vs the unsharded build.  The output deliberately carries NO
-        sharding constraint: an in-program re-scatter back-propagates
-        partitioning into the tail arithmetic (different reduction
-        order → ±ulp drift), so the caller re-scatters outside the
-        executable with ``device_put`` instead."""
+    def _meshed_build(self, build):
+        """Wrap a chunk-body builder for mesh execution.  The carry enters
+        sharded at rest; the body runs under ``shard_map`` with every
+        operand and result replicated, so the program gathers the carry
+        at entry and each device runs the unsharded body on whole arrays
+        — pure data movement, CPU-bitwise vs the unsharded build.
+        ``shard_map`` is also what lets the body hold Pallas kernels: the
+        TPU compiler refuses to partition a Mosaic call on its own.  The
+        caller re-scatters the output to the at-rest placement with
+        ``device_put`` between executables."""
         if self._mesh is None:
             return build
-        shardings = self._carry_shardings(carry, n_lead)
-        replicated = jax.tree.map(
-            lambda _: NamedSharding(self._mesh, P()), shardings,
-            is_leaf=lambda x: isinstance(x, NamedSharding))
+        mesh = self._mesh
 
         def wrapped_build():
-            fn = build()
-
-            def meshed(carry, *rest):
-                carry = jax.lax.with_sharding_constraint(carry, replicated)
-                return fn(carry, *rest)
-
-            return meshed
+            return jax.shard_map(build(), mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False)
 
         return wrapped_build
 
@@ -414,8 +420,7 @@ class JaxTrainer(TrainerBackend):
         key = ("fused", opt_name, n_steps, slab_sig, hp_sig, donate,
                self._mesh_key, self.use_scan)
         build = self._meshed_build(
-            lambda: self._make_chunk_body(opt_name, n_steps), args[0],
-            n_lead=0)
+            lambda: self._make_chunk_body(opt_name, n_steps))
         return self._call_executable(key, build, donate, args)
 
     def _call_group(self, opt_name: str, group: int, n_steps: int,
@@ -449,9 +454,8 @@ class JaxTrainer(TrainerBackend):
 
             return grouped
 
-        return self._call_executable(
-            key, self._meshed_build(build, args[0], n_lead=1), self._donate,
-            args)
+        return self._call_executable(key, self._meshed_build(build),
+                                     self._donate, args)
 
     # -------------------------------------------------------------- execute
     def run_stage(self, state: Dict[str, Any], ctx: StageContext
@@ -530,6 +534,9 @@ class JaxTrainer(TrainerBackend):
             if opt is None or s["opt_name"] != opt_name:
                 opt = init_opt_state(opt_name, s["params"])
             opt_l.append(opt)
+        if self._device is not None:
+            # members may arrive from the store (host) or another worker
+            params_l, opt_l = jax.device_put((params_l, opt_l), self._device)
         # siblings forked from one checkpoint share the data stream: one
         # pipeline (and one slab, broadcast in-executable) serves them all
         shared_data = group > 1 and all(
@@ -560,16 +567,16 @@ class JaxTrainer(TrainerBackend):
                 c = ch[j]
                 vals, static_hp, opt_n, names = pl[j]
                 if (c.start, c.stop) != (ctx0.start, ctx0.stop):
-                    raise ValueError("batched stages must share [start, stop)")
+                    raise BatchIncompatible("batched stages must share [start, stop)")
                 if opt_n != stage_opt or static_hp != static_hp0:
-                    raise ValueError("batched stages must share static hps")
+                    raise BatchIncompatible("batched stages must share static hps")
                 if names != names0:
-                    raise ValueError("batched stages must share hp names")
+                    raise BatchIncompatible("batched stages must share hp names")
                 if self._bs_runs(vals, n) != runs:
-                    raise ValueError("batched stages must share the bs schedule")
+                    raise BatchIncompatible("batched stages must share the bs schedule")
             if j == 0 and runs and runs[0][2] is None and len(pipes) > 1:
                 if len({p.batch_size for p in pipes}) > 1:
-                    raise ValueError("batched stages must share the batch size")
+                    raise BatchIncompatible("batched stages must share the batch size")
             if stage_opt != opt_name:
                 # optimizer switch at the boundary: fresh slots, exactly as
                 # run_stage would re-init on the restored state
@@ -592,8 +599,10 @@ class JaxTrainer(TrainerBackend):
                 for k_len in chunk_lengths(i1 - i0, self.chunk_steps):
                     w1 = w0 + k_len
                     slabs = [pipe.next_batches(k_len) for pipe in pipes]
-                    steps = jnp.arange(ctx0.start + w0, ctx0.start + w1,
-                                       dtype=jnp.int32)
+                    # host-side like the slabs: transferred to wherever
+                    # the executable runs
+                    steps = np.arange(ctx0.start + w0, ctx0.start + w1,
+                                      dtype=np.int32)
                     if group == 1:
                         hp_xs = {k: np.asarray(vals0[k][w0:w1], np.float32)
                                  for k in names0}
@@ -614,9 +623,8 @@ class JaxTrainer(TrainerBackend):
                             hp_sig, shared_data,
                             (carry, static_hp0, hp_xs, slab, steps))
                     if carry_shd is not None:
-                        # re-scatter to the at-rest placement OUTSIDE the
-                        # executable (see _meshed_build: an in-program
-                        # output constraint would cost bit-exactness)
+                        # re-scatter to the at-rest placement between
+                        # executables (see _meshed_build)
                         carry = jax.device_put(carry, carry_shd)
                     first = False
                     w0 = w1

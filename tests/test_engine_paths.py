@@ -1,10 +1,12 @@
 """Engine corner paths: mid-chain kills, truncated-parent deferral,
 trial-based salting, and checkpoint GC."""
 
+import pytest
+
 from repro.core import SearchPlan, SearchPlanDB, Study
 from repro.core.engine import Aggregator, EngineStats, EventLoop, ExecutionEngine, Tuner
 from repro.core.hpseq import Constant, HpConfig, MultiStep
-from repro.core.trainer import SimulatedTrainer
+from repro.core.trainer import BatchIncompatible, SimulatedTrainer
 from repro.core.trial import Trial
 from repro.core.tuners import GridTuner, SHATuner
 from repro.train.checkpoint import CheckpointStore
@@ -172,3 +174,69 @@ def test_sha_run_reclaims_loser_checkpoints():
     for node in plan.nodes.values():       # dead nodes hold no checkpoints
         if node.refcount <= 0:
             assert node.ckpts == {}
+
+
+# ---------------------------------------------------------------------------
+# batched/fused backend calls: only BatchIncompatible falls back
+# ---------------------------------------------------------------------------
+
+
+class _RaisingBackend(SimulatedTrainer):
+    """Batches sibling groups and fuses chains, but every such call raises
+    ``exc`` (solo per-stage execution still works)."""
+
+    supports_batched_stages = True
+    supports_chain_fusion = True
+
+    def __init__(self, exc, where):
+        super().__init__()
+        self.exc, self.where = exc, where
+
+    def run_stages_batched(self, states, ctxs):
+        if self.where == "batched":
+            raise self.exc
+        return super().run_stages_batched(states, ctxs)
+
+    def run_chains_batched(self, states, chains):
+        if self.where == "batched":
+            raise self.exc
+        return super().run_chains_batched(states, chains)
+
+    def run_chain(self, state, ctxs):
+        if self.where == "chain":
+            raise self.exc
+        return super().run_chain(state, ctxs)
+
+
+def _forked_siblings_run(backend):
+    """Three trials sharing 20 steps, then forking: one worker trains the
+    prefix, and the three tails form a sibling group the next round."""
+    plan = SearchPlan()
+    trials = [Trial(HpConfig({"lr": MultiStep(0.1, [20], values=[0.1, v])}),
+                    40) for v in (0.05, 0.02, 0.01)]
+    eng = ExecutionEngine(plan, backend, n_workers=1, batch_siblings=True)
+    return eng.run([GridTuner(trials)])
+
+
+@pytest.mark.parametrize("where", ["batched", "chain"])
+def test_value_error_from_batched_or_fused_call_propagates(where):
+    """A ValueError inside a batched or fused backend call, such as the
+    compiler refusing a kernel's lowering, is a fatal fault: it surfaces
+    from the run instead of quietly turning into member-sequential or
+    per-stage execution."""
+    backend = _RaisingBackend(ValueError("kernel lowering refused"), where)
+    with pytest.raises(ValueError, match="kernel lowering refused"):
+        _forked_siblings_run(backend)
+
+
+def test_batch_incompatible_runs_members_one_at_a_time():
+    """The trainer's in-flight incompatibility signal still degrades a
+    group to member-sequential execution with the same results."""
+    ref = _forked_siblings_run(SimulatedTrainer())
+    got = _forked_siblings_run(
+        _RaisingBackend(BatchIncompatible("divergent batch sizes"),
+                        "batched"))
+    assert ref.batched_groups >= 1
+    assert got.batched_groups == 0
+    assert got.steps_run == ref.steps_run
+    assert got.stages_run == ref.stages_run

@@ -590,7 +590,18 @@ def test_sigterm_graceful_shutdown_snapshot(tmp_path):
                             stderr=subprocess.STDOUT, text=True)
     try:
         import time
-        time.sleep(2.5)                    # a few throttled steps in
+        # the handler goes in after the imports, which take seconds on a
+        # loaded machine: wait until the process catches SIGTERM
+        # (SigCgt in /proc), then let a few throttled steps run
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline and proc.poll() is None:
+            with open(f"/proc/{proc.pid}/status") as f:
+                caught = next(int(l.split()[1], 16) for l in f
+                              if l.startswith("SigCgt:"))
+            if caught >> (signal.SIGTERM - 1) & 1:
+                break
+            time.sleep(0.05)
+        time.sleep(0.5)
         proc.send_signal(signal.SIGTERM)
         out, _ = proc.communicate(timeout=60)
     finally:

@@ -304,9 +304,11 @@ def test_one_device_mesh_workers_bitwise_equal_thread_workers(setup):
         stats = eng.run([GridTuner(list(trials))])
         return db.get(study.key), eng, stats
 
-    from repro.dist.meshes import plan_worker_meshes
+    # one device here: both width-1 workers own device 0 (a fleet spread
+    # over several devices is tests/test_meshplane.py's subprocess check)
+    from repro.dist.meshes import WorkerMesh
     plan_t, eng_t, stats_t = run(None)
-    plan_m, eng_m, stats_m = run(plan_worker_meshes(2, 1))
+    plan_m, eng_m, stats_m = run([WorkerMesh.build([0])] * 2)
 
     assert stats_m.mesh_placements > 0
     assert stats_t.mesh_placements == 0

@@ -579,3 +579,76 @@ def test_jax_backend_divisibility_gate():
     # cached per mesh key
     assert tb._mesh_ok[four.key] is True
     assert tb._mesh_ok[three.key] is False
+
+
+_FLEET_SCRIPT = """
+import sys
+sys.path.insert(0, {src!r})
+sys.path.insert(0, {tests!r})
+import jax
+assert jax.device_count() == 4, jax.device_count()
+from test_dataplane import tiny_backend, assert_states_identical
+from repro.core import SearchPlanDB, Study
+from repro.core.hpseq import Constant, HpConfig
+from repro.core.trial import Trial
+from repro.core.tuners import GridTuner
+from repro.dist.meshes import plan_worker_meshes
+
+def run(meshes):
+    db = SearchPlanDB()
+    study = Study.create(db, "m", "d", ("lr",))
+    trials = [Trial(HpConfig({{"lr": Constant(v)}}), 16)
+              for v in (0.1, 0.05, 0.02, 0.01)]
+    backend = tiny_backend()
+    placed = {{}}   # worker mesh key -> devices its boundary states live on
+    run_chain = backend._run_fused_chain
+
+    def spy(states, chains):
+        out = run_chain(states, chains)
+        devs = placed.setdefault(backend._mesh_key, set())
+        for member in out:
+            for st in member:
+                for leaf in jax.tree.leaves((st["params"], st["opt"])):
+                    devs |= set(leaf.devices())
+        return out
+
+    backend._run_fused_chain = spy
+    # four independent trials, no batching: one chain per worker
+    eng = study.engine(backend, n_workers=4, batch_siblings=False,
+                       worker_meshes=meshes)
+    eng.run([GridTuner(trials)])
+    return db.get(study.key), eng, trials, placed
+
+plan_t, eng_t, trials, placed_t = run(None)
+assert set(placed_t) == {{None}}, placed_t
+meshes = plan_worker_meshes(4, 1)
+plan_m, eng_m, _, placed_m = run(meshes)
+devices = jax.devices()
+for m in meshes:
+    assert placed_m.get(m.key) == {{devices[m.device_ids[0]]}}, (m, placed_m)
+assert len(set.union(*placed_m.values())) == 4, placed_m
+for t in trials:
+    leaf = plan_m.trial_paths[t.trial_id][-1]
+    assert_states_identical(eng_m.store.get(plan_m.nodes[leaf].ckpts[16]),
+                            eng_t.store.get(plan_t.nodes[leaf].ckpts[16]))
+print("FLEET-SPREAD-OK")
+"""
+
+
+def test_one_chip_worker_fleet_spreads_over_devices(tmp_path):
+    """A fleet of four 1-device worker meshes places each worker's carry
+    and boundary states on the device that worker owns (four distinct
+    devices, not device 0 four times), bit-identical to thread workers.
+    Runs in a subprocess: the forced host-device count must precede jax
+    import."""
+    script = tmp_path / "fleet_spread.py"
+    script.write_text(_FLEET_SCRIPT.format(
+        src=os.path.join(REPO, "src"), tests=os.path.join(REPO, "tests")))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                        + env.get("XLA_FLAGS", ""))
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, str(script)], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "FLEET-SPREAD-OK" in proc.stdout
