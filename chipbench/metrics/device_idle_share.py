@@ -1,0 +1,7 @@
+"""Device: share of the traced window in which no operation ran."""
+
+
+def read(view):
+    if view.window_s <= 0 or view.busy_s <= 0:
+        return None
+    return 1.0 - view.busy_s / view.window_s
