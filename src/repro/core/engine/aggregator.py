@@ -19,15 +19,27 @@ Under the write-behind checkpoint plane those suffix evictions may hit
 checkpoints whose host commit is still in flight — ``store.evict`` cancels
 the pending write (the bytes are never materialized), which is exactly the
 GC-correct outcome.
+
+A result's wait is timed here too, on the host clock: each waiter is
+stamped when it is added, each ``stage`` event carries the start of the
+work unit that produced it (``t_unit``), and a delivery adds the time from
+the request to that start (``result_wait_seconds``, clamped at 0: the unit
+may already be running) and the rest up to the tuner
+(``result_run_seconds``).  The waiters' stamps live beside the waiter
+table, not in it, so a session snapshot never holds them; a waiter without
+one (made before a restore) is not counted.  These are statistics only:
+no scheduling decision reads them.
 """
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Set, Tuple
 
 from repro.core.searchplan import SearchPlan
 from repro.core.engine.events import EventLoop
 from repro.train.checkpoint import CheckpointStore
+from repro.utils.spans import span
 
 __all__ = ["Aggregator"]
 
@@ -42,25 +54,40 @@ class Aggregator:
         # (node_id, step) -> list of (handle, trial) waiting on the result
         self.waiters: Dict[Tuple[str, int], List[Tuple[Any, Any]]] = {}
         self.killed: Set[str] = set()
+        # (node_id, step) -> {(study_id, trial_id): host time of the request}
+        self._stamps: Dict[Tuple[str, int], Dict[Tuple[str, str], float]] = {}
 
     # -------------------------------------------------------------- waiters
     def add_waiter(self, node_id: str, step: int, handle, trial) -> None:
         self.waiters.setdefault((node_id, step), []).append((handle, trial))
+        self._stamps.setdefault((node_id, step), {})[
+            (handle.study_id, trial.trial_id)] = time.perf_counter()
 
     # ----------------------------------------------------------- aggregation
     def on_stage_done(self, p: Dict[str, Any]) -> None:
         self.plan.record_result(p["node_id"], p["stop"], p["cid"], p["metrics"])
         if p["metrics"] is not None:
             key = (p["node_id"], p["stop"])
+            stamps = self._stamps.pop(key, {})
             for handle, trial in self.waiters.pop(key, []):
                 if trial.trial_id not in self.killed:
-                    handle.tuner.on_result(trial, p["stop"], p["metrics"])
+                    asked = stamps.get((handle.study_id, trial.trial_id))
+                    if asked is not None:
+                        self._time_result(asked, p.get("t_unit", asked))
+                    with span("hippo.tuner.on_result"):
+                        handle.tuner.on_result(trial, p["stop"], p["metrics"])
         if self.plan.nodes[p["node_id"]].refcount <= 0:
             # result for a node killed while running — nothing will resume
             # from it, reclaim the checkpoint immediately
             self._evict_node(p["node_id"])
         if p["last"]:
             self.events.push(self.events.time, "idle", p["worker"])
+
+    def _time_result(self, asked: float, started: float) -> None:
+        now = time.perf_counter()
+        self.stats.result_wait_seconds += max(0.0, started - asked)
+        self.stats.result_run_seconds += max(0.0, now - max(asked, started))
+        self.stats.results_timed += 1
 
     def detach_study(self, study_id: str) -> None:
         """Cancel path: drop every waiter belonging to ``study_id`` and
@@ -73,6 +100,7 @@ class Aggregator:
             ws[:] = [(h, t) for (h, t) in ws if h.study_id != study_id]
             if not ws:
                 del self.waiters[key]
+                self._stamps.pop(key, None)
                 nid, step = key
                 node = self.plan.nodes[nid]
                 if (step in node.requests and step not in node.running
@@ -99,6 +127,7 @@ class Aggregator:
                 if not ws and s not in node.running and s not in node.metrics:
                     self.plan.drop_request(nid, s)
                     self.waiters.pop(key, None)
+                    self._stamps.pop(key, None)
         for nid in dead:
             self._evict_node(nid)
 

@@ -69,6 +69,7 @@ from repro.core.trainer import (BatchIncompatible, StageContext,
                                  TrainerBackend)
 from repro.dist.meshes import WorkerMesh
 from repro.train.checkpoint import CheckpointStore
+from repro.utils.spans import span
 
 __all__ = ["Worker", "Dispatcher"]
 
@@ -157,8 +158,11 @@ class Dispatcher:
         # and leaves its requests pending with the worker still idle: re-run
         # the round so Algorithm 1 re-derives them.  Each retry forgets at
         # least one stale checkpoint entry, so the loop terminates.
-        while self._assign_round():
-            pass
+        while True:
+            with span("hippo.dispatch.round"):
+                again = self._assign_round()
+            if not again:
+                break
         self._sync_kernel_stats()
         self._sync_store_stats()
         self._sync_fault_stats()
@@ -233,7 +237,8 @@ class Dispatcher:
         idle = [w for w in self.workers if w.idle and not w.draining]
         if not idle:
             return False
-        tree = self.builder.build()
+        with span("hippo.stagetree.build"):
+            tree = self.builder.build()
         if not tree.stages:
             return False
         self.stats.rounds += 1
@@ -244,21 +249,8 @@ class Dispatcher:
         taken: set = set()
 
         if self.batch_siblings:
-            if self.chain_fusion:
-                # groups extend down parallel chains with identical
-                # per-stage signatures (batched multi-stage chains); the
-                # per-dispatch work cap applies to them like any chain
-                groups = sibling_chain_groups(self.plan, tree)
-                if self.max_steps_per_chain:
-                    # members share per-level step counts, so one member's
-                    # truncation depth bounds the whole group; cut levels
-                    # were never claimed and reschedule in a later round
-                    cuts = [len(self._truncate(g[0])) for g in groups]
-                    groups = [[c[:cut] for c in g]
-                              for g, cut in zip(groups, cuts)]
-            else:
-                groups = [[[st] for st in g]
-                          for g in sibling_groups(self.plan, tree)]
+            with span("hippo.scheduler.assign"):
+                groups = self._sibling_units(tree)
             for group in groups:
                 if not idle:
                     break
@@ -288,8 +280,9 @@ class Dispatcher:
             nonlocal exhausted
             if exhausted or not pool:
                 return
-            got = self.scheduler.assign(self.plan, tree, len(pool),
-                                        taken=taken)
+            with span("hippo.scheduler.assign"):
+                got = self.scheduler.assign(self.plan, tree, len(pool),
+                                            taken=taken)
             if len(got) < len(pool):
                 exhausted = True
             pending.extend(got)
@@ -323,6 +316,23 @@ class Dispatcher:
                 refill()
         return missed and any(w.idle and not w.draining
                               for w in self.workers)
+
+    def _sibling_units(self, tree) -> List[List[List[Stage]]]:
+        """The round's sibling groups, each a list of member chains."""
+        if not self.chain_fusion:
+            return [[[st] for st in g]
+                    for g in sibling_groups(self.plan, tree)]
+        # groups extend down parallel chains with identical per-stage
+        # signatures (batched multi-stage chains); the per-dispatch work
+        # cap applies to them like any chain
+        groups = sibling_chain_groups(self.plan, tree)
+        if self.max_steps_per_chain:
+            # members share per-level step counts, so one member's
+            # truncation depth bounds the whole group; cut levels were
+            # never claimed and reschedule in a later round
+            cuts = [len(self._truncate(g[0])) for g in groups]
+            groups = [[c[:cut] for c in g] for g, cut in zip(groups, cuts)]
+        return groups
 
     # -------------------------------------------------------------- placement
     def _place(self, candidates: List[Worker],
@@ -389,21 +399,23 @@ class Dispatcher:
         cid = self.plan.node(nid).ckpts.get(step)
         if cid is None:
             return None
-        if self._d2d_enabled and worker is not None:
-            entry = self._d2d.get(cid)
-            if entry is not None and entry[1] == worker.host:
-                moved = self.backend.device_transfer(entry[0], worker.mesh)
-                if moved is not None:
-                    self._d2d.move_to_end(cid)
-                    self.stats.d2d_handoffs += 1
-                    return moved, cid
-        t0 = _time.perf_counter()
-        try:
-            return self.store.get(cid), cid
-        except KeyError:
-            pass
-        finally:
-            self.stats.ckpt_load_seconds += _time.perf_counter() - t0
+        with span("hippo.ckpt.load"):
+            if self._d2d_enabled and worker is not None:
+                entry = self._d2d.get(cid)
+                if entry is not None and entry[1] == worker.host:
+                    moved = self.backend.device_transfer(entry[0],
+                                                         worker.mesh)
+                    if moved is not None:
+                        self._d2d.move_to_end(cid)
+                        self.stats.d2d_handoffs += 1
+                        return moved, cid
+            t0 = _time.perf_counter()
+            try:
+                return self.store.get(cid), cid
+            except KeyError:
+                pass
+            finally:
+                self.stats.ckpt_load_seconds += _time.perf_counter() - t0
         self.stats.ckpt_misses += 1
         self.plan.forget_ckpt(nid, step)
         return None
@@ -428,13 +440,14 @@ class Dispatcher:
         if self._injector is not None:
             self._assert_retry_identical(path_key, stop, state)
         t0 = _time.perf_counter()
-        if self.chain_fusion:
-            cid = self.store.put_async(path_key, stop, state,
-                                       parent_cid=parent_cid)
-            self.stats.ckpt_async_writes += 1
-        else:
-            cid = self.store.put(path_key, stop, state,
-                                 parent_cid=parent_cid)
+        with span("hippo.ckpt.put"):
+            if self.chain_fusion:
+                cid = self.store.put_async(path_key, stop, state,
+                                           parent_cid=parent_cid)
+                self.stats.ckpt_async_writes += 1
+            else:
+                cid = self.store.put(path_key, stop, state,
+                                     parent_cid=parent_cid)
         self.stats.ckpt_save_seconds += _time.perf_counter() - t0
         self.stats.ckpt_saves += 1
         return cid
@@ -622,6 +635,16 @@ class Dispatcher:
         the worker returns to the pool).  A failure mid-execution returns
         ``"ran"``: the worker burned time on the attempt and its idle
         event is scheduled by the failure domain."""
+        with span("hippo.dispatch.unit", width=1, depth=len(path),
+                  steps=sum(st.steps for st in path)):
+            return self._chain_unit(path, worker, produced,
+                                    _time.perf_counter())
+
+    def _chain_unit(self, path: List[Stage], worker: Worker,
+                    produced: Dict[str, Tuple[Any, float, Optional[str]]],
+                    t_unit: float) -> str:
+        """The body of :meth:`_execute_chain`; ``t_unit`` is the unit's
+        start on the host clock, carried by its ``stage`` events."""
         head = path[0]
         t = max(self.events.time, worker.busy_until)
         load_s, save_s = self.backend.overheads()
@@ -672,7 +695,7 @@ class Dispatcher:
             self.stats.mesh_placements += 1
         if self.chain_fusion:
             self._run_chain_fused(path, worker, state, t, produced,
-                                  parent_cid)
+                                  parent_cid, t_unit)
             return "ran"
 
         for i, st in enumerate(path):
@@ -722,7 +745,7 @@ class Dispatcher:
             self.events.push(t, "stage", {
                 "node_id": st.node_id, "stop": st.stop, "cid": cid,
                 "metrics": metrics, "worker": worker.wid,
-                "last": st is path[-1]})
+                "last": st is path[-1], "t_unit": t_unit})
         worker.busy_until = t
         self._worker_recovered(worker)
         self._unit_succeeded(path)
@@ -733,7 +756,8 @@ class Dispatcher:
                          state: Any, t: float,
                          produced: Dict[str, Tuple[Any, float,
                                                    Optional[str]]],
-                         parent_cid: Optional[str] = None) -> None:
+                         parent_cid: Optional[str],
+                         t_unit: float) -> None:
         """Execute the whole chain through ``backend.run_chain``: one fused
         call, device-resident carry across boundaries, write-behind
         checkpoints — with per-stage events, profiles and virtual durations
@@ -808,7 +832,7 @@ class Dispatcher:
             self.events.push(t, "stage", {
                 "node_id": st.node_id, "stop": st.stop, "cid": cid,
                 "metrics": metrics, "worker": worker.wid,
-                "last": st is path[-1]})
+                "last": st is path[-1], "t_unit": t_unit})
         worker.busy_until = t
         self._worker_recovered(worker)
         self._unit_succeeded(path)
@@ -828,6 +852,17 @@ class Dispatcher:
         group is refunded and its stages fall through to the ordinary
         chain scheduler this round.
         """
+        with span("hippo.dispatch.unit", width=len(group),
+                  depth=len(group[0]),
+                  steps=sum(st.steps for st in group[0])):
+            return self._group_unit(group, worker, produced, taken,
+                                    _time.perf_counter())
+
+    def _group_unit(self, group: List[List[Stage]], worker: Worker,
+                    produced: Dict[str, Tuple[Any, float, Optional[str]]],
+                    taken: set, t_unit: float) -> Tuple[bool, bool]:
+        """The body of :meth:`_execute_group`; ``t_unit`` is the unit's
+        start on the host clock, carried by its ``stage`` events."""
         t = max(self.events.time, worker.busy_until)
         load_s, save_s = self.backend.overheads()
         gpus = self._worker_gpus(worker)
@@ -1028,7 +1063,8 @@ class Dispatcher:
                     # a crash during degradation delays the idle event to
                     # probation (pushed below) instead of the last stage
                     "last": crash_rejoin is None and j == depth - 1
-                            and m == len(members) - 1})
+                            and m == len(members) - 1,
+                    "t_unit": t_unit})
         if batched:
             self.stats.batched_groups += 1
             self.stats.batched_stages += len(members) * depth

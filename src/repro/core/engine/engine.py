@@ -71,6 +71,7 @@ from repro.core.faults import FaultyBackend, FaultyStore
 from repro.core.trainer import TrainerBackend
 from repro.core.trial import Trial
 from repro.train.checkpoint import CheckpointStore
+from repro.utils.spans import span
 
 __all__ = ["ExecutionEngine", "Tuner", "StudyHandle", "EngineStats",
            "StudyStats"]
@@ -157,9 +158,10 @@ class EngineStats:
     ckpt_misses: int = 0      # vanished resume ckpts degraded to recompute
     chain_fused_stages: int = 0   # stages advanced via backend.run_chain(s)
     ckpt_async_writes: int = 0    # write-behind boundary checkpoints
-    kernel_calls: int = 0         # kernel-plane call sites traced (backend-
-                                  # cumulative; see JaxTrainer.kernel_calls)
-    kernel_fallbacks: int = 0     # kernel→oracle fallbacks traced
+    kernel_calls: int = 0         # kernel call sites per compilation, not
+                                  # per launch (backend-cumulative; see
+                                  # JaxTrainer.kernel_calls)
+    kernel_fallbacks: int = 0     # kernel→oracle fallbacks, likewise
     ckpt_save_seconds: float = 0.0  # synchronous slice of store puts
     ckpt_load_seconds: float = 0.0  # store gets (resume loads)
     # ---- distribution plane v2 (mesh workers; see dispatch.py) ----
@@ -194,6 +196,15 @@ class EngineStats:
     faults_injected: int = 0        # injector faults fired (delta-mirrored
                                     # like the store counters)
     wasted_gpu_seconds: float = 0.0  # GPU time burned by failed attempts
+    # ---- a result's wait, on the host clock (see Aggregator): from the
+    # request to the start of the work unit that serves it, then from
+    # there to the tuner.  Process-local statistics: no scheduling
+    # decision reads them, a restored session counts only requests made
+    # after the restore, and they stay out of equality (the replay
+    # contract of snapshot/restore). ----
+    result_wait_seconds: float = field(default=0.0, compare=False)
+    result_run_seconds: float = field(default=0.0, compare=False)
+    results_timed: int = field(default=0, compare=False)
     by_study: Dict[str, StudyStats] = field(default_factory=dict)
 
     @property
@@ -401,6 +412,7 @@ class ExecutionEngine:
             # §3.2: results already present → respond immediately (still an
             # event so tuner callbacks observe a consistent clock).
             self.stats.study(handle.study_id).instant_results += 1
+            self.stats.results_timed += 1     # no wait, no run
             metrics = self.plan.metrics_for(node.node_id, step)
             self.events.push(self.events.time, "reply",
                              (handle, trial, step, metrics))
@@ -434,13 +446,20 @@ class ExecutionEngine:
         if not self.events:
             return False
         ev = self.events.pop()
+        with span("hippo.engine.step", kind=ev.kind):
+            self._handle(ev)
+            self.dispatcher.assign()
+        return True
+
+    def _handle(self, ev) -> None:
         if ev.kind == "stage":
             self.aggregator.on_stage_done(ev.payload)
         elif ev.kind == "reply":
             handle, trial, step, metrics = ev.payload
             if (trial.trial_id not in self.aggregator.killed
                     and handle.study_id not in self._cancelled):
-                handle.tuner.on_result(trial, step, metrics)
+                with span("hippo.tuner.on_result"):
+                    handle.tuner.on_result(trial, step, metrics)
         elif ev.kind == "idle":
             # keyed by wid, not list index: dynamic fleets (front-door
             # leases) remove workers mid-session, so positions shift and
@@ -473,8 +492,6 @@ class ExecutionEngine:
                 if nxt.kind != "admit" or nxt.time > self.events.time:
                     break
                 self._start_handle(self.events.pop().payload)
-        self.dispatcher.assign()
-        return True
 
     def drain(self) -> None:
         """Run to quiescence (the legacy ``_drain`` loop, re-entrant)."""

@@ -52,6 +52,7 @@ from repro.core.scheduler import (CriticalPathScheduler, SchedulingPolicy,
                                   make_policy)
 from repro.core.trainer import TrainerBackend
 from repro.train.checkpoint import CheckpointStore
+from repro.utils.spans import span
 
 __all__ = ["Study", "StudySpec", "StudyFuture", "StudyService",
            "PlanKeyMismatch", "run_studies"]
@@ -352,31 +353,33 @@ class StudyService:
         in-flight stage forest — the admission event wakes the dispatcher;
         no fresh ``run()`` is needed, and results the plan already holds
         answer instantly."""
-        eng = self._ensure_engine(self._key_of(study))
-        taken = {f.study_id for f in self._futures}
-        if study_id is None:
-            n = len(self._futures)
-            while f"study-{n}" in taken:   # skip explicitly-supplied ids
-                n += 1
-            sid = f"study-{n}"
-        elif study_id in taken:
-            raise ValueError(f"study id {study_id!r} already submitted")
-        else:
-            sid = study_id
-        h = eng.admit(tuner, sid, at=at)
-        fut = StudyFuture(self, sid, self._key, tuner,
-                          arrival=at if at is not None else eng.time)
-        self._futures.append(fut)
-        return fut
+        with span("hippo.service.submit"):
+            eng = self._ensure_engine(self._key_of(study))
+            taken = {f.study_id for f in self._futures}
+            if study_id is None:
+                n = len(self._futures)
+                while f"study-{n}" in taken:   # skip explicitly-supplied ids
+                    n += 1
+                sid = f"study-{n}"
+            elif study_id in taken:
+                raise ValueError(f"study id {study_id!r} already submitted")
+            else:
+                sid = study_id
+            h = eng.admit(tuner, sid, at=at)
+            fut = StudyFuture(self, sid, self._key, tuner,
+                              arrival=at if at is not None else eng.time)
+            self._futures.append(fut)
+            return fut
 
     # ------------------------------------------------------------ the session
     def step(self) -> bool:
         """Advance the session by one event (False at quiescence)."""
-        if self._engine is None or not self._engine.step():
-            return False
-        self._refresh_futures()
-        self._maybe_auto_snapshot()
-        return True
+        with span("hippo.service.step"):
+            if self._engine is None or not self._engine.step():
+                return False
+            self._refresh_futures()
+            self._maybe_auto_snapshot()
+            return True
 
     def run_until(self, t: float) -> None:
         """Drive every event scheduled at or before virtual time ``t``."""
@@ -404,13 +407,14 @@ class StudyService:
         """Drain, then terminate: flush the write-behind store, stamp
         ``end_to_end``, journal the plan.  Flushing happens even when the
         drain errors (the durability barrier of ``ExecutionEngine.run``)."""
-        try:
-            self.join()
-        finally:
-            self._closed = True
-            if self._engine is not None:
-                self._engine.finish()
-                self.db.checkpoint(self._key)
+        with span("hippo.service.close"):
+            try:
+                self.join()
+            finally:
+                self._closed = True
+                if self._engine is not None:
+                    self._engine.finish()
+                    self.db.checkpoint(self._key)
         return self.stats
 
     def __enter__(self) -> "StudyService":

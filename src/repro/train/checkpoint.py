@@ -77,6 +77,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.utils.spans import span
+
 try:  # jax is always present in this repo, but the store works without it
     import jax
     _HAVE_JAX = True
@@ -459,47 +461,48 @@ class CheckpointStore:
                     parent_cid = self._pending_parent.get(cid)
                 if tree is None:
                     continue  # superseded (a revoked re-put already committed)
-                try:
-                    staged = (self._serialize_disk(cid, tree, parent_cid)
-                              if self.directory else None)
-                except BaseException as e:  # surfaced at the next flush()
-                    with self._cv:
-                        self._write_error = e
-                        self._pending.pop(cid, None)
-                        self._pending_parent.pop(cid, None)
-                        self._cancelled.discard(cid)
-                        self._cv.notify_all()
-                    continue
-                with self._cv:
+                with span("hippo.ckpt.write"):
                     try:
-                        if cid in self._cancelled:
-                            # evicted while serializing: the commit never
-                            # publishes — the final path is untouched, only
-                            # temps to discard
-                            self._cancelled.discard(cid)
-                            if staged is not None:
-                                os.remove(staged.tmp)
-                        else:
-                            # publish + state transition in ONE critical
-                            # section so __len__ never sees a cid as both
-                            # pending and on disk
-                            if staged is not None:
-                                self._publish_disk(cid, staged)
-                            elif cid in self._pending:
-                                self._mem[cid] = tree
+                        staged = (self._serialize_disk(cid, tree, parent_cid)
+                                  if self.directory else None)
+                    except BaseException as e:  # surfaced at the next flush()
+                        with self._cv:
+                            self._write_error = e
                             self._pending.pop(cid, None)
                             self._pending_parent.pop(cid, None)
-                    except BaseException as e:
-                        # a publish/cancel failure must never strand the
-                        # cid in _pending/_cancelled: flush() would
-                        # deadlock instead of surfacing the error
-                        self._write_error = e
-                        self._pending.pop(cid, None)
-                        self._pending_parent.pop(cid, None)
-                        self._cancelled.discard(cid)
-                    finally:
-                        self._cv.notify_all()
-                self._demote_excess()
+                            self._cancelled.discard(cid)
+                            self._cv.notify_all()
+                        continue
+                    with self._cv:
+                        try:
+                            if cid in self._cancelled:
+                                # evicted while serializing: the commit
+                                # never publishes — the final path is
+                                # untouched, only temps to discard
+                                self._cancelled.discard(cid)
+                                if staged is not None:
+                                    os.remove(staged.tmp)
+                            else:
+                                # publish + state transition in ONE critical
+                                # section so __len__ never sees a cid as both
+                                # pending and on disk
+                                if staged is not None:
+                                    self._publish_disk(cid, staged)
+                                elif cid in self._pending:
+                                    self._mem[cid] = tree
+                                self._pending.pop(cid, None)
+                                self._pending_parent.pop(cid, None)
+                        except BaseException as e:
+                            # a publish/cancel failure must never strand the
+                            # cid in _pending/_cancelled: flush() would
+                            # deadlock instead of surfacing the error
+                            self._write_error = e
+                            self._pending.pop(cid, None)
+                            self._pending_parent.pop(cid, None)
+                            self._cancelled.discard(cid)
+                        finally:
+                            self._cv.notify_all()
+                    self._demote_excess()
         except BaseException as e:
             # unexpected thread death (anything the per-item handlers above
             # did not catch): surface at the next flush() and make sure the

@@ -75,9 +75,16 @@ All four execution paths (``run_stage``, ``run_stages_batched``,
 ``run_chain``, ``run_chains_batched``) share the same chunk bodies, so
 they are uniformly kernel-aware; on the vmapped sibling-group path the
 kernels' batching rules fold the member axis into the kernel grid (one
-launch per group).  ``kernel_calls`` / ``kernel_fallbacks`` expose the
-kernel plane's trace-time counters (cumulative since this trainer's
-construction) for ``EngineStats``.
+launch per group).  ``kernel_calls`` / ``kernel_fallbacks`` count kernel
+call sites per compilation, not per launch (they move while a body is
+traced; cumulative since this trainer's construction), for
+``EngineStats``.  How often and how long the optimizer kernel ran is in
+a device trace, under the ``hippo.opt_update`` scope.
+
+Names in a profiler trace: the compiled callables are ``hippo_chunk``
+(solo), ``hippo_group`` (sibling group) and ``hippo_eval``, so the
+device's modules are ``jit_hippo_chunk`` and so on, and each host-side
+step of a call is a ``hippo.trainer.*`` span (:mod:`repro.utils.spans`).
 
 Mesh workers (distribution plane v2): :meth:`set_mesh` binds the trainer
 to the dispatching worker's :class:`~repro.dist.meshes.WorkerMesh` before
@@ -119,6 +126,7 @@ from repro.kernels import ops as kernel_ops
 from repro.kernels.optim import fused_apply_update
 from repro.train.checkpoint import stack_pytrees, unstack_pytree
 from repro.train.optimizer import apply_update, init_opt_state
+from repro.utils.spans import span
 
 __all__ = ["JaxTrainer", "chunk_lengths"]
 
@@ -135,6 +143,16 @@ def chunk_lengths(n: int, max_chunk: int) -> List[int]:
         out.append(c)
         n -= c
     return out
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under a fixed name, which ``jax.jit`` gives the module it
+    compiles (``jit_<name>``), so a device trace says what ran."""
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 class JaxTrainer(TrainerBackend):
@@ -179,7 +197,7 @@ class JaxTrainer(TrainerBackend):
         # buffer donation frees the carry between chunks; XLA:CPU does not
         # implement it (and warns per call), so gate on the backend
         self._donate = accel if donate is None else donate
-        self._eval_fn = jax.jit(self.task.loss)
+        self._eval_fn = jax.jit(_named(self.task.loss, "hippo_eval"))
         # Cumulative seconds spent AOT-compiling chunk executables.  The
         # dispatcher subtracts the per-stage delta from its measured wall so
         # one-time compilation never pollutes seconds/step profiles or the
@@ -197,8 +215,10 @@ class JaxTrainer(TrainerBackend):
     # ------------------------------------------------- kernel-plane counters
     @property
     def kernel_calls(self) -> int:
-        """Kernel-plane call sites traced since construction (counters move
-        at trace time: constant per compilation, not per step)."""
+        """Kernel call sites per compilation since construction, not
+        launches: the counter moves while a chunk body is traced.  The
+        optimizer kernel's launches are the ``hippo.opt_update`` scope's
+        operations in a device trace."""
         return kernel_ops.KERNEL_STATS.calls - self._kernel_stats0[0]
 
     @property
@@ -310,8 +330,9 @@ class JaxTrainer(TrainerBackend):
 
     # ------------------------------------------------------------------ state
     def init_state(self) -> Dict[str, Any]:
-        params = self.task.init(jax.random.PRNGKey(self.seed))
-        pipe = self.pipeline_factory()
+        with span("hippo.trainer.init_state"):
+            params = self.task.init(jax.random.PRNGKey(self.seed))
+            pipe = self.pipeline_factory()
         return {
             "params": params,
             "opt": None,               # lazy: optimizer choice is a static hp
@@ -371,8 +392,9 @@ class JaxTrainer(TrainerBackend):
                     hp.update(hp_i)
                     (loss, _), grads = jax.value_and_grad(
                         task.loss, has_aux=True)(params, batch)
-                    params, opt = update(opt_name, params, grads, opt,
-                                         hp, step)
+                    with jax.named_scope("hippo.opt_update"):
+                        params, opt = update(opt_name, params, grads, opt,
+                                             hp, step)
                     return (params, opt), loss
 
                 carry, losses = jax.lax.scan(body, carry,
@@ -391,8 +413,9 @@ class JaxTrainer(TrainerBackend):
                 batch = {k: v[i] for k, v in slab.items()}
                 (loss, _), grads = jax.value_and_grad(
                     task.loss, has_aux=True)(params, batch)
-                params, opt = update(opt_name, params, grads, opt,
-                                     hp, steps[i])
+                with jax.named_scope("hippo.opt_update"):
+                    params, opt = update(opt_name, params, grads, opt,
+                                         hp, steps[i])
             return (params, opt), loss
 
         chunk.uses_scan = False
@@ -400,20 +423,29 @@ class JaxTrainer(TrainerBackend):
 
     def _call_executable(self, key: Tuple, build, donate: bool, args: Tuple):
         """Invoke the cached executable for ``key``, AOT-compiling on miss.
+        The compiled callable is named for its kind (``key[0]``): a solo
+        chunk is ``hippo_chunk``, a sibling group ``hippo_group``.
 
         Ahead-of-time ``lower().compile()`` (instead of first-call jit
         compilation) lets compilation time be accounted separately in
         ``compile_seconds`` — the dispatcher's wall-clock stage timing
         subtracts it, keeping profiles and virtual time execution-only."""
+        # members and steps of the call: ("fused", opt, k, ...) or
+        # ("group", opt, m, k, ...)
+        m, k = (1, key[2]) if key[0] == "fused" else (key[2], key[3])
         exe = self._chunk_fns.get(key)
         if exe is None:
-            t0 = time.perf_counter()
-            jitted = jax.jit(build(), donate_argnums=(0,) if donate else ())
-            exe = jitted.lower(*args).compile()
-            self.compile_seconds += time.perf_counter() - t0
+            with span("hippo.trainer.compile", m=m, k=k):
+                t0 = time.perf_counter()
+                name = "hippo_chunk" if key[0] == "fused" else "hippo_group"
+                jitted = jax.jit(_named(build(), name),
+                                 donate_argnums=(0,) if donate else ())
+                exe = jitted.lower(*args).compile()
+                self.compile_seconds += time.perf_counter() - t0
             self._chunk_fns[key] = exe
         self.exec_calls += 1
-        return exe(*args)
+        with span("hippo.trainer.launch", m=m, k=k):
+            return exe(*args)
 
     def _call_fused(self, opt_name: str, n_steps: int, slab_sig: Tuple,
                     hp_sig: Tuple, donate: bool, args: Tuple):
@@ -515,47 +547,50 @@ class JaxTrainer(TrainerBackend):
         for ch in chains[1:]:
             if len(ch) != depth:
                 raise ValueError("batched chains must share their depth")
-        plans = [[self._stage_plan(c) for c in ch] for ch in chains]
-        for ch in chains:   # stages of one chain must be contiguous
-            step = ch[0].start
-            for c in ch:
-                if c.start != step:
-                    raise ValueError(
-                        f"chain stages must be contiguous: stage starts at "
-                        f"{c.start}, previous stopped at {step}")
-                step = c.stop
+        with span("hippo.trainer.prepare", m=group, depth=depth):
+            plans = [[self._stage_plan(c) for c in ch] for ch in chains]
+            for ch in chains:   # stages of one chain must be contiguous
+                step = ch[0].start
+                for c in ch:
+                    if c.start != step:
+                        raise ValueError(
+                            f"chain stages must be contiguous: stage starts "
+                            f"at {c.start}, previous stopped at {step}")
+                    step = c.stop
 
-        opt_name = plans[0][0][2]
-        params_l, opt_l = [], []
-        for s, ch in zip(states, chains):
-            assert s["step"] == ch[0].start, (s["step"], ch[0].start)
-            params_l.append(s["params"])
-            opt = s["opt"]
-            if opt is None or s["opt_name"] != opt_name:
-                opt = init_opt_state(opt_name, s["params"])
-            opt_l.append(opt)
-        if self._device is not None:
-            # members may arrive from the store (host) or another worker
-            params_l, opt_l = jax.device_put((params_l, opt_l), self._device)
-        # siblings forked from one checkpoint share the data stream: one
-        # pipeline (and one slab, broadcast in-executable) serves them all
-        shared_data = group > 1 and all(
-            tuple(s["data"]) == tuple(states[0]["data"]) for s in states[1:])
-        pipes = []
-        for s in (states[:1] if shared_data else states):
-            pipe = self.pipeline_factory()
-            pipe.restore(s["data"])
-            pipes.append(pipe)
+            opt_name = plans[0][0][2]
+            params_l, opt_l = [], []
+            for s, ch in zip(states, chains):
+                assert s["step"] == ch[0].start, (s["step"], ch[0].start)
+                params_l.append(s["params"])
+                opt = s["opt"]
+                if opt is None or s["opt_name"] != opt_name:
+                    opt = init_opt_state(opt_name, s["params"])
+                opt_l.append(opt)
+            if self._device is not None:
+                # members may arrive from the store (host) or another worker
+                params_l, opt_l = jax.device_put((params_l, opt_l),
+                                                 self._device)
+            # siblings forked from one checkpoint share the data stream: one
+            # pipeline (and one slab, broadcast in-executable) serves them all
+            shared_data = group > 1 and all(
+                tuple(s["data"]) == tuple(states[0]["data"])
+                for s in states[1:])
+            pipes = []
+            for s in (states[:1] if shared_data else states):
+                pipe = self.pipeline_factory()
+                pipe.restore(s["data"])
+                pipes.append(pipe)
 
-        if group == 1:
-            carry = (params_l[0], opt_l[0])
-        else:
-            carry = (stack_pytrees(params_l), stack_pytrees(opt_l))
-        n_lead = 0 if group == 1 else 1   # member-stack axis never shards
-        carry_shd = None                  # at-rest NamedSharding tree
-        if self._mesh is not None:
-            carry_shd = self._carry_shardings(carry, n_lead)
-            carry = jax.device_put(carry, carry_shd)
+            if group == 1:
+                carry = (params_l[0], opt_l[0])
+            else:
+                carry = (stack_pytrees(params_l), stack_pytrees(opt_l))
+            n_lead = 0 if group == 1 else 1   # member-stack axis never shards
+            carry_shd = None                  # at-rest NamedSharding tree
+            if self._mesh is not None:
+                carry_shd = self._carry_shardings(carry, n_lead)
+                carry = jax.device_put(carry, carry_shd)
         boundaries: List[List[Dict[str, Any]]] = [[] for _ in range(group)]
 
         for j in range(depth):
@@ -598,26 +633,30 @@ class JaxTrainer(TrainerBackend):
                 w0 = i0
                 for k_len in chunk_lengths(i1 - i0, self.chunk_steps):
                     w1 = w0 + k_len
-                    slabs = [pipe.next_batches(k_len) for pipe in pipes]
-                    # host-side like the slabs: transferred to wherever
-                    # the executable runs
-                    steps = np.arange(ctx0.start + w0, ctx0.start + w1,
-                                      dtype=np.int32)
+                    with span("hippo.trainer.feed", k=k_len):
+                        slabs = [pipe.next_batches(k_len) for pipe in pipes]
+                        # host-side like the slabs: transferred to wherever
+                        # the executable runs
+                        steps = np.arange(ctx0.start + w0, ctx0.start + w1,
+                                          dtype=np.int32)
+                        if group == 1:
+                            hp_xs = {k: np.asarray(vals0[k][w0:w1],
+                                                   np.float32)
+                                     for k in names0}
+                        else:
+                            hp_xs = {k: np.asarray([pl[j][0][k][w0:w1]
+                                                    for pl in plans],
+                                                   np.float32)
+                                     for k in names0}
+                            slab = (slabs[0] if shared_data else
+                                    {k: np.stack([s[k] for s in slabs])
+                                     for k in slabs[0]})
                     if group == 1:
-                        hp_xs = {k: np.asarray(vals0[k][w0:w1], np.float32)
-                                 for k in names0}
                         carry, _ = self._call_fused(
                             opt_name, k_len, self._slab_sig(slabs[0]), hp_sig,
                             self._donate and not first,
                             (carry, static_hp0, hp_xs, slabs[0], steps))
                     else:
-                        hp_xs = {k: np.asarray([pl[j][0][k][w0:w1]
-                                                for pl in plans],
-                                               np.float32)
-                                 for k in names0}
-                        slab = (slabs[0] if shared_data else
-                                {k: np.stack([s[k] for s in slabs])
-                                 for k in slabs[0]})
                         carry, _ = self._call_group(
                             opt_name, group, k_len, self._slab_sig(slabs[0]),
                             hp_sig, shared_data,
@@ -631,24 +670,25 @@ class JaxTrainer(TrainerBackend):
 
             # ---- boundary snapshot: per-member state the dispatcher can
             # checkpoint; the carry itself stays on device for stage j+1
-            if group == 1:
-                params_out, opt_out = [carry[0]], [carry[1]]
-            else:
-                params_out = unstack_pytree(carry[0], group)
-                opt_out = unstack_pytree(carry[1], group)
-            if self._mesh is not None:
-                # snapshots leave the trainer unsharded: checkpoints, eval
-                # and cross-worker handoff all see single-device trees
-                dev = self._mesh.devices.flat[0]
-                params_out = [jax.device_put(p, dev) for p in params_out]
-                opt_out = [jax.device_put(o, dev) for o in opt_out]
-            datas = ([pipes[0].state()] * group if shared_data
-                     else [p.state() for p in pipes])
-            for m in range(group):
-                boundaries[m].append(
-                    {"params": params_out[m], "opt": opt_out[m],
-                     "opt_name": opt_name, "data": datas[m],
-                     "step": ctx0.stop})
+            with span("hippo.trainer.snapshot", m=group):
+                if group == 1:
+                    params_out, opt_out = [carry[0]], [carry[1]]
+                else:
+                    params_out = unstack_pytree(carry[0], group)
+                    opt_out = unstack_pytree(carry[1], group)
+                if self._mesh is not None:
+                    # snapshots leave the trainer unsharded: checkpoints, eval
+                    # and cross-worker handoff all see single-device trees
+                    dev = self._mesh.devices.flat[0]
+                    params_out = [jax.device_put(p, dev) for p in params_out]
+                    opt_out = [jax.device_put(o, dev) for o in opt_out]
+                datas = ([pipes[0].state()] * group if shared_data
+                         else [p.state() for p in pipes])
+                for m in range(group):
+                    boundaries[m].append(
+                        {"params": params_out[m], "opt": opt_out[m],
+                         "opt_name": opt_name, "data": datas[m],
+                         "step": ctx0.stop})
         return boundaries
 
     # ----------------------------------------------- seed per-step reference
@@ -700,11 +740,13 @@ class JaxTrainer(TrainerBackend):
     # ------------------------------------------------------------- evaluate
     def evaluate(self, state: Dict[str, Any], ctx: StageContext
                  ) -> Dict[str, float]:
-        loss, metrics = self._eval_fn(state["params"], self.eval_batch)
-        out = {"loss": float(loss)}
-        out["val_acc"] = float(metrics.get(self.objective_from, -loss))
-        for k, v in metrics.items():
-            out[k] = float(v)
+        with span("hippo.trainer.eval"):
+            loss, metrics = self._eval_fn(state["params"], self.eval_batch)
+        with span("hippo.trainer.eval_wait"):
+            out = {"loss": float(loss)}
+            out["val_acc"] = float(metrics.get(self.objective_from, -loss))
+            for k, v in metrics.items():
+                out[k] = float(v)
         return out
 
     def stage_seconds(self, ctx: StageContext) -> Optional[float]:
