@@ -81,10 +81,28 @@ traced; cumulative since this trainer's construction), for
 ``EngineStats``.  How often and how long the optimizer kernel ran is in
 a device trace, under the ``hippo.opt_update`` scope.
 
+Tree programs: the host-side tree work between executables is one
+compiled dispatch each, never a loop of per-leaf eager ops — parameter
+initialisation (``hippo_init``), an optimizer's zero slots
+(``hippo_slots``), stacking a unit's member ``(params, opt)`` trees
+(``hippo_stack``) and splitting the carry into member trees at each
+boundary (``hippo_unstack``).  Slots, stacking and splitting are exact
+data movement, bit for bit the eager ops'; the compiled init draws the
+same random numbers, though XLA may fold a constant scale of a draw into
+the sampler's own and round it an ulp or two apart (the ResNet's init
+matches bit for bit).  JAX's jit cache keys the programs by member
+count, tree, avals and optimizer name, so the warm-up's fresh states of
+each width compile everything a study needs: members that arrive with
+slots from a boundary, or as host numpy from a store tier, reuse the
+same executables (a 1-device worker places its members on its device
+before the stack, so its stack too sees one placement).
+``tree_calls`` counts their dispatches beside ``exec_calls``.
+
 Names in a profiler trace: the compiled callables are ``hippo_chunk``
-(solo), ``hippo_group`` (sibling group) and ``hippo_eval``, so the
-device's modules are ``jit_hippo_chunk`` and so on, and each host-side
-step of a call is a ``hippo.trainer.*`` span (:mod:`repro.utils.spans`).
+(solo), ``hippo_group`` (sibling group), ``hippo_eval`` and the tree
+programs above, so the device's modules are ``jit_hippo_chunk`` and so
+on, and each host-side step of a call is a ``hippo.trainer.*`` span
+(:mod:`repro.utils.spans`).
 
 Mesh workers (distribution plane v2): :meth:`set_mesh` binds the trainer
 to the dispatching worker's :class:`~repro.dist.meshes.WorkerMesh` before
@@ -110,6 +128,7 @@ unsharded.  The live mesh key joins every executable cache key.
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -155,6 +174,32 @@ def _named(fn: Callable, name: str) -> Callable:
     return named
 
 
+# ------------------------------------------------------------ tree programs
+# Module-level, so JAX's cache serves every trainer and study alike.
+
+@partial(jax.jit, static_argnums=0)
+@partial(_named, name="hippo_slots")
+def _init_slots(opt_name: str, params: Any) -> Dict[str, Any]:
+    """The optimizer's fresh slots for ``params`` (member-stacked or not)."""
+    return init_opt_state(opt_name, params)
+
+
+@jax.jit
+@partial(_named, name="hippo_stack")
+def _stack_members(members: List[Tuple[Any, Any]]) -> Tuple[Any, Any]:
+    """``m`` member ``(params, opt)`` trees -> one member-stacked carry."""
+    return stack_pytrees(members)
+
+
+@partial(jax.jit, static_argnums=1)
+@partial(_named, name="hippo_unstack")
+def _unstack_members(carry: Tuple[Any, Any], m: int
+                     ) -> List[Tuple[Any, Any]]:
+    """A member-stacked carry -> its ``m`` member ``(params, opt)`` trees
+    (not donated: the carry runs on into the next stage)."""
+    return unstack_pytree(carry, m)
+
+
 class JaxTrainer(TrainerBackend):
     """Stage executor over any task exposing ``init(rng)`` and
     ``loss(params, batch) -> (scalar, metrics)``."""
@@ -198,12 +243,14 @@ class JaxTrainer(TrainerBackend):
         # implement it (and warns per call), so gate on the backend
         self._donate = accel if donate is None else donate
         self._eval_fn = jax.jit(_named(self.task.loss, "hippo_eval"))
+        self._init_fn = jax.jit(_named(self.task.init, "hippo_init"))
         # Cumulative seconds spent AOT-compiling chunk executables.  The
         # dispatcher subtracts the per-stage delta from its measured wall so
         # one-time compilation never pollutes seconds/step profiles or the
         # virtual clock (a deployment amortizes compiles across the study).
         self.compile_seconds = 0.0
         self.exec_calls = 0       # compiled-executable dispatches issued
+        self.tree_calls = 0       # tree-program dispatches issued
         # -------- mesh plane (distribution plane v2; see module docstring)
         self._wmesh = None                      # live WorkerMesh (>1 device)
         self._mesh = None                       # its jax.sharding.Mesh
@@ -331,7 +378,10 @@ class JaxTrainer(TrainerBackend):
     # ------------------------------------------------------------------ state
     def init_state(self) -> Dict[str, Any]:
         with span("hippo.trainer.init_state"):
-            params = self.task.init(jax.random.PRNGKey(self.seed))
+            # the key is built on the host: a seed past 2**31 would not
+            # survive as a traced int32
+            params = self._init_fn(jax.random.PRNGKey(self.seed))
+            self.tree_calls += 1
             pipe = self.pipeline_factory()
         return {
             "params": params,
@@ -375,6 +425,10 @@ class JaxTrainer(TrainerBackend):
                      for k, v in sorted(slab.items()))
 
     # ------------------------------------------------------------ executables
+    def _slots(self, opt_name: str, params: Any) -> Dict[str, Any]:
+        self.tree_calls += 1
+        return _init_slots(opt_name, params)
+
     def _make_chunk_body(self, opt_name: str, n_steps: int):
         """The fused stage body: ``n_steps`` training steps over the
         slab/hp/step arrays.  Statically unrolled on CPU (bit-exact vs the
@@ -538,9 +592,12 @@ class JaxTrainer(TrainerBackend):
 
         The carry — ``(params, opt)``, member-stacked for groups — and the
         data pipelines persist across stage boundaries; each boundary only
-        snapshots the carry (for groups: per-member gathers off the stack)
-        so the dispatcher can checkpoint it, then execution continues on
-        device.  ``group == 1, depth == 1`` degenerates to the old fused
+        snapshots the carry (for groups: one ``hippo_unstack`` dispatch
+        splits it into member trees) so the dispatcher can checkpoint it,
+        then execution continues on device.  Before the first stage, the
+        members' missing optimizer slots come from ``hippo_slots`` and a
+        group's members are stacked by one ``hippo_stack`` dispatch.
+        ``group == 1, depth == 1`` degenerates to the old fused
         single-stage path, ``group > 1, depth == 1`` to sibling batching."""
         group = len(states)
         depth = len(chains[0])
@@ -565,7 +622,7 @@ class JaxTrainer(TrainerBackend):
                 params_l.append(s["params"])
                 opt = s["opt"]
                 if opt is None or s["opt_name"] != opt_name:
-                    opt = init_opt_state(opt_name, s["params"])
+                    opt = self._slots(opt_name, s["params"])
                 opt_l.append(opt)
             if self._device is not None:
                 # members may arrive from the store (host) or another worker
@@ -585,7 +642,8 @@ class JaxTrainer(TrainerBackend):
             if group == 1:
                 carry = (params_l[0], opt_l[0])
             else:
-                carry = (stack_pytrees(params_l), stack_pytrees(opt_l))
+                carry = _stack_members(list(zip(params_l, opt_l)))
+                self.tree_calls += 1
             n_lead = 0 if group == 1 else 1   # member-stack axis never shards
             carry_shd = None                  # at-rest NamedSharding tree
             if self._mesh is not None:
@@ -615,7 +673,7 @@ class JaxTrainer(TrainerBackend):
             if stage_opt != opt_name:
                 # optimizer switch at the boundary: fresh slots, exactly as
                 # run_stage would re-init on the restored state
-                carry = (carry[0], init_opt_state(stage_opt, carry[0]))
+                carry = (carry[0], self._slots(stage_opt, carry[0]))
                 opt_name = stage_opt
                 if carry_shd is not None:    # fresh slots: back to at-rest
                     carry_shd = self._carry_shardings(carry, n_lead)
@@ -672,21 +730,20 @@ class JaxTrainer(TrainerBackend):
             # checkpoint; the carry itself stays on device for stage j+1
             with span("hippo.trainer.snapshot", m=group):
                 if group == 1:
-                    params_out, opt_out = [carry[0]], [carry[1]]
+                    members = [carry]
                 else:
-                    params_out = unstack_pytree(carry[0], group)
-                    opt_out = unstack_pytree(carry[1], group)
+                    members = _unstack_members(carry, group)
+                    self.tree_calls += 1
                 if self._mesh is not None:
                     # snapshots leave the trainer unsharded: checkpoints, eval
                     # and cross-worker handoff all see single-device trees
                     dev = self._mesh.devices.flat[0]
-                    params_out = [jax.device_put(p, dev) for p in params_out]
-                    opt_out = [jax.device_put(o, dev) for o in opt_out]
+                    members = [jax.device_put(mb, dev) for mb in members]
                 datas = ([pipes[0].state()] * group if shared_data
                          else [p.state() for p in pipes])
-                for m in range(group):
+                for m, (params, opt) in enumerate(members):
                     boundaries[m].append(
-                        {"params": params_out[m], "opt": opt_out[m],
+                        {"params": params, "opt": opt,
                          "opt_name": opt_name, "data": datas[m],
                          "step": ctx0.stop})
         return boundaries

@@ -30,7 +30,7 @@ SPANS = (
     "hippo.ckpt.put",           # synchronous slice of a boundary save
     "hippo.ckpt.load",          # a resume load
     "hippo.ckpt.write",         # the write-behind thread's commit
-    "hippo.trainer.init_state",  # eager initialisation
+    "hippo.trainer.init_state",  # parameter initialisation
     "hippo.trainer.prepare",    # plans, optimizer init, placement, stacking
     "hippo.trainer.feed",       # one chunk's slabs and hp arrays
     "hippo.trainer.launch",     # enqueue of one chunk executable
